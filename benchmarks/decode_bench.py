@@ -29,6 +29,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import SageStore, reset_trace_counts, trace_counts
 from repro.core import refdec
 from repro.core.decode_jax import (
@@ -182,6 +183,7 @@ def main(argv=None) -> int:
     ap.add_argument("--depth", type=float, default=None)
     ap.add_argument("--iters", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ref_len = args.ref_len or (12_000 if args.smoke else 120_000)
     depth = args.depth or (2 if args.smoke else 4)

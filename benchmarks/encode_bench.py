@@ -33,6 +33,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import refdec, reset_trace_counts, trace_counts
 from repro.core.encoder import SageEncoder
 from repro.genomics.synth import ReadSet, make_reference, sample_read_set
@@ -118,6 +119,7 @@ def main(argv=None) -> int:
     ap.add_argument("--depth", type=float, default=None)
     ap.add_argument("--iters", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ref_len = args.ref_len or (12_000 if args.smoke else 120_000)
     depth = args.depth or (2 if args.smoke else 4)

@@ -47,6 +47,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import SageStore, Scrubber
 from repro.core.encoder import SageEncoder
 from repro.core.errors import IntegrityError, SageIOError
@@ -325,6 +326,7 @@ def main(argv=None) -> int:
     ap.add_argument("--trials", type=int, default=None)
     ap.add_argument("--ref-len", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ref_len = args.ref_len or (12_000 if args.smoke else 40_000)
     trials = args.trials or (6 if args.smoke else 25)

@@ -44,6 +44,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import SageStore
 from repro.core.format import D, STREAMS, SageFile
 from repro.core.layout import SageContainerV2, write_v2
@@ -323,6 +324,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workdir", default=None, help="container scratch dir")
     ap.add_argument("--k", type=int, default=4, help="ranged-read block count")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ref_len = 12_000 if args.smoke else 120_000
     depth = 2 if args.smoke else 6

@@ -12,7 +12,9 @@ import time
 
 def main() -> None:
     from benchmarks import paper_figs, roofline
+    from repro.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     sections = [
         ("fig03", paper_figs.fig03_rows),
         ("fig12", paper_figs.fig12_rows),

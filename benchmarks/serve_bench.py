@@ -36,6 +36,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import reset_trace_counts, trace_counts
 from repro.genomics.synth import make_reference, sample_read_set
 from repro.serving import SageServer, SessionPool
@@ -247,6 +248,7 @@ def main(argv=None) -> int:
     ap.add_argument("--requests", type=int, default=None)
     ap.add_argument("--ref-len", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ref_len = args.ref_len or (12_000 if args.smoke else 60_000)
     n_requests = args.requests or (15 if args.smoke else 60)

@@ -54,6 +54,9 @@ def main(argv=None) -> int:
 
     if args.force_devices:
         _force_host_devices(args.force_devices)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
     import numpy as np

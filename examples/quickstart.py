@@ -17,11 +17,13 @@ sys.path.insert(0, "src")
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import SageStore
 from repro.genomics.synth import make_reference, sample_read_set
 
 
 def main() -> None:
+    enable_compile_cache()
     print("=== SAGe quickstart ===")
     ref = make_reference(80_000, seed=7)
     rs = sample_read_set(ref, "illumina", depth=8, seed=8)

@@ -11,12 +11,14 @@ import time
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import SageStore
 from repro.genomics.mapper import map_store_reads
 from repro.genomics.synth import make_reference, sample_read_set
 
 
 def main() -> None:
+    enable_compile_cache()
     print("=== SAGe -> read-mapping pipeline ===")
     ref = make_reference(60_000, seed=21)
     rs = sample_read_set(ref, "illumina", depth=3, seed=22)

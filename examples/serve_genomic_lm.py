@@ -16,6 +16,7 @@ sys.path.insert(0, "src")
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.genomics.synth import make_reference, sample_read_set
 from repro.models import lm
@@ -23,6 +24,7 @@ from repro.serving import SageServer, ServeConfig, ServingEngine, SessionPool
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_arch("qwen2-1.5b").reduced()
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     eng = ServingEngine(cfg, params, ServeConfig(max_prompt=48, max_new=16))

@@ -20,6 +20,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.core import SageStore
 from repro.data.pipeline import SageTokenPipeline
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--layers", type=int, default=4, help="reduced depth")
     ap.add_argument("--ckpt-dir", default="/tmp/genomic_lm_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if not args.full:

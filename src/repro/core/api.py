@@ -104,7 +104,7 @@ def one_hot_bases(tokens: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
 class FormatSpec:
     """One SAGe_Read output format.
 
-    ``apply(tokens, *, kmer_k, use_pallas, interpret, n_tokens)`` converts
+    ``apply(tokens, *, kmer_k, use_pallas, n_tokens)`` converts
     decoded base tokens into the format's array (``n_tokens`` is the decode
     dict's per-row real-token count, for formats that must tell tail PAD
     from in-read N); ``None`` means the raw 2-bit tokens are already the
@@ -117,19 +117,19 @@ class FormatSpec:
     doc: str = ""
 
 
-def _apply_one_hot(tokens, *, kmer_k=None, use_pallas=False, interpret=True, n_tokens=None):
+def _apply_one_hot(tokens, *, kmer_k=None, use_pallas=False, n_tokens=None):
     if use_pallas:
         from repro.kernels.reformat import one_hot_pallas
 
-        return one_hot_pallas(tokens, interpret=interpret)
+        return one_hot_pallas(tokens)
     return one_hot_bases(tokens)
 
 
-def _apply_kmer(tokens, *, kmer_k, use_pallas=False, interpret=True, n_tokens=None):
+def _apply_kmer(tokens, *, kmer_k, use_pallas=False, n_tokens=None):
     if use_pallas:
         from repro.kernels.reformat import kmer_pack_pallas
 
-        return kmer_pack_pallas(tokens, kmer_k, n_tokens, interpret=interpret)
+        return kmer_pack_pallas(tokens, kmer_k, n_tokens)
     return kmer_pack(tokens, kmer_k, n_tokens)
 
 
@@ -172,7 +172,6 @@ def apply_format(
     *,
     kmer_k: Optional[int] = None,
     use_pallas: bool = False,
-    interpret: bool = True,
     context: str = "sage_read",
 ) -> dict[str, jax.Array]:
     """Attach ``fmt``'s array to a decode result dict (in place) and return it."""
@@ -185,7 +184,7 @@ def apply_format(
     if spec.apply is not None:
         out[spec.out_key] = spec.apply(
             out["tokens"], kmer_k=kmer_k, use_pallas=use_pallas,
-            interpret=interpret, n_tokens=out.get("n_tokens"),
+            n_tokens=out.get("n_tokens"),
         )
     return out
 

@@ -39,7 +39,6 @@ from repro.distributed.sharding import (
     block_axis_name,
     block_shard_count,
     block_specs,
-    shard_map,
 )
 
 PAD_BASE = 4  # output padding token
@@ -685,7 +684,7 @@ def _build_sharded_decode(mesh: Mesh, caps_h, classes_key, fixed_len, decoder_ke
         sub = jax.lax.with_sharding_constraint(sub, block_specs(sub, mesh))
         # check_vma=False: pallas_call has no replication rule; every in/out
         # is fully block-sharded so replication checking is vacuous here
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=PartitionSpec(axis),
             out_specs=PartitionSpec(axis), check_vma=False,
         )(sub)
@@ -849,7 +848,7 @@ def fused_decode_blocks_bucketed(
 
     Same pad/mask/slice invariants (compiles once per bucket), bit-identical
     outputs; ``path_key`` selects the runner (None = the fused vmap jit;
-    ``("pallas", (("interpret", x),))`` = the fused Pallas kernel registered
+    ``("pallas", ())`` = the fused Pallas kernel registered
     by repro.kernels.sage_decode)."""
     if fmt_name not in _FORMAT_FUSERS:
         raise KeyError(

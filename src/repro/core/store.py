@@ -72,6 +72,7 @@ from repro.distributed.sharding import (
     block_sharding,
     make_block_mesh,
 )
+from repro.kernels import mode as kernel_mode
 
 BlockRange = Union[None, int, tuple, Sequence[int]]
 
@@ -155,6 +156,10 @@ class SageStore:
         if unpack_impl not in ("jnp", "pallas"):
             raise ValueError(
                 f"unpack_impl must be 'jnp' or 'pallas', got {unpack_impl!r}"
+            )
+        if unpack_impl == "pallas":
+            kernel_mode.require_pallas(
+                [kernel_mode.UNPACK], 'SageStore(unpack_impl="pallas")'
             )
         self.max_prepared = max_prepared
         self.unpack_impl = unpack_impl
@@ -1054,7 +1059,6 @@ class SageStore:
         self,
         *,
         use_pallas: bool = False,
-        interpret: bool = True,
         mesh: Optional[Mesh] = None,
         shards: Optional[int] = None,
         fused: bool = False,
@@ -1067,6 +1071,10 @@ class SageStore:
         one fused jit otherwise) — bit-identical output, fewer launches;
         formats without a registered fuser and mesh sessions transparently
         fall back to the two-step path.
+
+        ``use_pallas=True`` off the CPU backend raises
+        :class:`repro.kernels.mode.PallasUnavailableError` (no Pallas kernel
+        compiles for the chip yet).
 
         On a sharded store the only valid overrides are the store's own mesh
         or the single-device path: resident arrays are committed to the
@@ -1083,9 +1091,7 @@ class SageStore:
                 "re-shard by building a store with the desired mesh, or pass "
                 "shards=1 for the single-device decode path"
             )
-        return SageReadSession(
-            self, use_pallas=use_pallas, interpret=interpret, mesh=m, fused=fused
-        )
+        return SageReadSession(self, use_pallas=use_pallas, mesh=m, fused=fused)
 
 
 class SageReadSession:
@@ -1099,13 +1105,18 @@ class SageReadSession:
         store: SageStore,
         *,
         use_pallas: bool = False,
-        interpret: bool = True,
         mesh: Optional[Mesh] = None,
         fused: bool = False,
     ) -> None:
+        if use_pallas:
+            kernels = [kernel_mode.DECODE, kernel_mode.KMER, kernel_mode.ONE_HOT]
+            if fused:
+                kernels.insert(1, kernel_mode.FUSED)
+            kernel_mode.require_pallas(
+                kernels, f"a read session with use_pallas=True, fused={fused}"
+            )
         self.store = store
         self.use_pallas = use_pallas
-        self.interpret = interpret
         self.mesh = mesh
         self.fused = fused
 
@@ -1143,7 +1154,7 @@ class SageReadSession:
 
         return functools.partial(
             sage_decode_arrays, caps=db.caps, classes=db.classes,
-            fixed_len=db.fixed_len, interpret=self.interpret,
+            fixed_len=db.fixed_len,
         )
 
     def _decoder_key(self):
@@ -1153,7 +1164,7 @@ class SageReadSession:
             return None
         import repro.kernels.sage_decode  # noqa: F401  (registers "pallas")
 
-        return ("pallas", (("interpret", self.interpret),))
+        return ("pallas", ())
 
     def read(
         self,
@@ -1207,10 +1218,7 @@ class SageReadSession:
                     f"SAGe_Read({name!r}): format {spec.name!r} requires kmer_k "
                     f"(registered formats: {available_formats()})"
                 )
-            path_key = (
-                ("pallas", (("interpret", self.interpret),))
-                if self.use_pallas else ("vmap", ())
-            )
+            path_key = ("pallas", ()) if self.use_pallas else ("vmap", ())
             if self.use_pallas:
                 import repro.kernels.sage_decode  # noqa: F401  (registers "pallas")
             return fused_decode_blocks_bucketed(
@@ -1225,7 +1233,7 @@ class SageReadSession:
             db, local,
             postprocess=lambda dec: apply_format(
                 dec, fmt, kmer_k=kmer_k, use_pallas=self.use_pallas,
-                interpret=self.interpret, context=f"SAGe_Read({name!r})",
+                context=f"SAGe_Read({name!r})",
             ),
             **path,
         )

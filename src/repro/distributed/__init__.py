@@ -9,6 +9,5 @@ from repro.distributed.sharding import (
     make_block_mesh,
     param_shardings,
     shard_act,
-    shard_map,
     use_rules,
 )

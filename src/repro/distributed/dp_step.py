@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
 
 from repro.training.optimizer import adamw_update
 from repro.training.steps import TrainOptions, loss_fn
@@ -67,7 +66,7 @@ def make_dp_train_step(cfg, opts: TrainOptions, mesh, dp_axes: tuple[str, ...], 
 
     def train_step(params, opt, batch):
         ospec = {k: (jax.tree.map(lambda _: rep, v) if k != "ef" else jax.tree.map(lambda _: rep, v)) for k, v in opt.items()}
-        return shard_map(
+        return jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: rep, params), ospec, batch_spec(batch)),
             out_specs=(jax.tree.map(lambda _: rep, params), ospec, {"loss": rep, "grad_norm": rep, "lr": rep}),

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
 
 
 def pipeline_apply(
@@ -82,7 +81,7 @@ def pipeline_apply(
         jax.tree.map(lambda _: P(axis), stacked_params),
         P(),
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         pipelined, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False
     )
     return fn(stacked_params, x)

@@ -29,26 +29,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 top-level API
-    _shard_map = jax.shard_map
-    _SHARD_MAP_HAS_VMA = True
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_HAS_VMA = False
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True, **kw):
-    """Version-tolerant shard_map: ``jax.shard_map`` on new jax, the
-    experimental one on 0.4.x — where the varying-manual-axes check is
-    still called ``check_rep``. All repro code routes through this."""
-    if not _SHARD_MAP_HAS_VMA:
-        kw["check_rep"] = check_vma
-    else:
-        kw["check_vma"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 _state = threading.local()
 
 BLOCK_AXIS = "blocks"  # the store-level mesh axis (SAGe block partitions)

@@ -1,9 +1,10 @@
 """jit'd dispatch wrappers over the Pallas kernels with jnp fallbacks.
 
-``use_pallas`` selects the kernel path; on this CPU container kernels run in
-interpret mode (the validation bar); on real TPU the same calls lower via
-Mosaic. The jnp fallbacks are the ref.py oracles, so correctness is
-dispatch-invariant by construction (asserted in tests/test_kernels.py).
+``use_pallas`` selects the kernel path; the backend decides how a kernel
+runs (:mod:`repro.kernels.mode`: interpret mode on the CPU, the only place
+the kernels run today). The jnp fallbacks are the ref.py oracles, so
+correctness is dispatch-invariant by construction (asserted in
+tests/test_kernels.py).
 """
 
 from __future__ import annotations
@@ -20,26 +21,26 @@ from repro.kernels.ssd_chunk import ssd_intra_pallas
 F32 = jnp.float32
 
 
-def sage_decode(db: DeviceBlocks, *, use_pallas: bool = False, interpret: bool = True):
+def sage_decode(db: DeviceBlocks, *, use_pallas: bool = False):
     """Decode all blocks -> dict(tokens, read_pos, read_rev, ...)."""
     if use_pallas:
-        return sage_decode_pallas(db, interpret=interpret)
+        return sage_decode_pallas(db)
     return REF.sage_decode_ref(db)
 
 
-def kmer_tokens(tokens: jax.Array, k: int, *, use_pallas: bool = False, interpret: bool = True):
+def kmer_tokens(tokens: jax.Array, k: int, *, use_pallas: bool = False):
     if use_pallas:
-        return kmer_pack_pallas(tokens, k, interpret=interpret)
+        return kmer_pack_pallas(tokens, k)
     return REF.kmer_pack_ref(tokens, k)
 
 
-def one_hot(tokens: jax.Array, *, use_pallas: bool = False, interpret: bool = True):
+def one_hot(tokens: jax.Array, *, use_pallas: bool = False):
     if use_pallas:
-        return one_hot_pallas(tokens, interpret=interpret)
+        return one_hot_pallas(tokens)
     return REF.one_hot_ref(tokens)
 
 
-def ssd(x, dt, A, B_, C_, chunk: int, state0=None, *, use_pallas: bool = False, interpret: bool = True):
+def ssd(x, dt, A, B_, C_, chunk: int, state0=None, *, use_pallas: bool = False):
     """Full SSD: Pallas intra-chunk kernel + jnp inter-chunk recurrence.
 
     Mirrors repro.models.ssm.ssd_chunked exactly (same padding semantics)."""
@@ -62,7 +63,7 @@ def ssd(x, dt, A, B_, C_, chunk: int, state0=None, *, use_pallas: bool = False, 
     Bc = B_.reshape(Bb, nc, Q, H, N).astype(F32)
     Cc = C_.reshape(Bb, nc, Q, H, N).astype(F32)
 
-    y_intra, st_c, total = ssd_intra_pallas(xc, dtc, ac, Bc, Cc, interpret=interpret)
+    y_intra, st_c, total = ssd_intra_pallas(xc, dtc, ac, Bc, Cc)
 
     state0 = jnp.zeros((Bb, H, P, N), F32) if state0 is None else state0
 

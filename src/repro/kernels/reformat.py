@@ -5,10 +5,15 @@ Converts decoded base tokens into the accelerator's desired format:
     onto the assigned archs' vocabularies)
   * one-hot bf16 planes (the [106]-style format)
 
-Grid tiles the flat token stream; each step handles one (blocks_per_step ×
-TILE) slab in VMEM. Trivially parallel, MXU-free, VPU-bound. Like the decode
+The grid has one step per block; each step formats that block's (1, C)
+token row in VMEM. Trivially parallel, MXU-free, VPU-bound. Like the decode
 kernel, each ``pallas_call`` is built once per shape signature and wrapped in
 ``jax.jit`` so the store's bucketed reads never re-lower the formatter.
+
+Runs only in interpret mode, on the CPU backend (:mod:`repro.kernels.mode`).
+For a v5e, Mosaic refuses the (1, C) row blocks of any multi-block grid
+(tests/test_tpu_compile.py); at one block the k-mer body still fails on its
+row reshape, and only one-hot compiles.
 """
 
 from __future__ import annotations
@@ -21,6 +26,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.api import kmer_special_ids
 from repro.core.decode_jax import PAD_BASE, TRACE_COUNTS
+from repro.kernels.mode import interpret_mode
 
 
 def kmer_ids_row(t: jax.Array, k: int, n_tok) -> jax.Array:
@@ -86,7 +92,7 @@ def _build_kmer_pack(nb: int, C: int, k: int, with_ntok: bool, interpret: bool):
 
 
 def kmer_pack_pallas(
-    tokens: jax.Array, k: int, n_tokens: jax.Array | None = None, *, interpret: bool = True
+    tokens: jax.Array, k: int, n_tokens: jax.Array | None = None
 ) -> jax.Array:
     """tokens: (nb, C) int8 (+ per-block real-token counts (nb,)) ->
     (nb, C//k) int32. See :func:`repro.core.api.kmer_pack` for the
@@ -95,9 +101,9 @@ def kmer_pack_pallas(
     if nb == 0:  # a grid of zero steps cannot be built (or run)
         return jnp.zeros((0, C // k), jnp.int32)
     if n_tokens is None:
-        return _build_kmer_pack(nb, C, k, False, interpret)(tokens)
+        return _build_kmer_pack(nb, C, k, False, interpret_mode())(tokens)
     ntok = jnp.asarray(n_tokens, jnp.int32)[:, None]
-    return _build_kmer_pack(nb, C, k, True, interpret)(tokens, ntok)
+    return _build_kmer_pack(nb, C, k, True, interpret_mode())(tokens, ntok)
 
 
 def _onehot_kernel(tok_ref, out_ref):
@@ -124,9 +130,9 @@ def _build_one_hot(nb: int, C: int, interpret: bool):
     return run
 
 
-def one_hot_pallas(tokens: jax.Array, *, interpret: bool = True) -> jax.Array:
+def one_hot_pallas(tokens: jax.Array) -> jax.Array:
     """tokens: (nb, C) int8 -> (nb, C, 4) bf16 (PAD rows all-zero)."""
     nb, C = tokens.shape
     if nb == 0:  # a grid of zero steps cannot be built (or run)
         return jnp.zeros((0, C, 4), jnp.bfloat16)
-    return _build_one_hot(nb, C, interpret)(tokens)
+    return _build_one_hot(nb, C, interpret_mode())(tokens)

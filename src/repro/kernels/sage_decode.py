@@ -22,9 +22,11 @@ capacity (tokens<=16Ki, window<=1Mi bases), one grid step's working set is
 comfortably inside a v5e core's VMEM. Capacities are static (from SageMeta),
 so the same kernel serves any read set produced by the encoder.
 
-Validated in interpret mode (CPU container); Mosaic lowering notes: the body
-uses cumsum / sort-free gathers / scatters-with-drop, all expressible on TPU
-(gathers over VMEM-resident arrays; see DESIGN.md §2 hardware notes).
+Runs only in interpret mode, on the CPU backend (:mod:`repro.kernels.mode`).
+Mosaic refuses every kernel in this file for a v5e (tests/test_tpu_compile.py):
+the ``(1, w)`` row BlockSpecs break the rule that the second-minor block dim
+be a multiple of 8 or the whole array, and with one block per call the
+decode body still fails on its 1-D gathers and the unpack body on cumsum.
 """
 
 from __future__ import annotations
@@ -44,6 +46,7 @@ from repro.core.decode_jax import (
     register_shard_decoder,
 )
 from repro.core.format import D, STREAMS
+from repro.kernels.mode import interpret_mode
 
 OUT_KEYS = ("tokens", "read_pos", "read_rev", "read_start", "read_len", "read_corner")
 
@@ -96,7 +99,6 @@ def sage_decode_arrays(
     caps,
     classes: dict[str, tuple[int, ...]],
     fixed_len: int,
-    interpret: bool = True,
 ) -> dict[str, jax.Array]:
     """Decode block-major stream arrays (as gathered by the store's bucketed
     hot path) with the Pallas kernel. An optional ``arrays["valid"]`` column
@@ -109,16 +111,15 @@ def sage_decode_arrays(
     classes_key = tuple(sorted((k, tuple(v)) for k, v in classes.items()))
     run = _build_pallas_decode(
         _HashableCaps(caps), classes_key, fixed_len, nb,
-        tuple(a.shape[1] for a in ins), tuple(names), interpret,
+        tuple(a.shape[1] for a in ins), tuple(names), interpret_mode(),
     )
     return dict(zip(OUT_KEYS, run(*ins)))
 
 
-def sage_decode_pallas(db: DeviceBlocks, *, interpret: bool = True):
+def sage_decode_pallas(db: DeviceBlocks):
     """Decode all blocks of a prepared SageFile with one pallas_call."""
     return sage_decode_arrays(
-        db.arrays, caps=db.caps, classes=db.classes,
-        fixed_len=db.fixed_len, interpret=interpret,
+        db.arrays, caps=db.caps, classes=db.classes, fixed_len=db.fixed_len,
     )
 
 
@@ -127,17 +128,16 @@ def _build_pallas_shard_decoder(caps, classes, fixed_len, opts):
     its resident lane shard (grid = per-shard bucket size), so the kernel's
     lru signature is keyed on the *per-shard* block count and stays constant
     across shard counts that keep the same per-device bucket."""
-    interpret = bool(opts.get("interpret", True))
 
     def local(sub):
         return dict(sage_decode_arrays(
-            sub, caps=caps, classes=classes, fixed_len=fixed_len, interpret=interpret,
+            sub, caps=caps, classes=classes, fixed_len=fixed_len,
         ))
 
     return local
 
 
-# sessions select this path with decoder_key=("pallas", (("interpret", x),))
+# sessions select this path with decoder_key=("pallas", ())
 register_shard_decoder("pallas", _build_pallas_shard_decoder)
 
 
@@ -233,7 +233,6 @@ def _build_fused_gather_decode(
 def _build_pallas_fused(caps_h, classes_key, fixed_len, fmt_name, kmer_k, opts):
     """Fused-path builder for ``fused_decode_blocks_bucketed`` (the lru'd
     kernel build keys on the padded shapes, resolved at first call)."""
-    interpret = bool(opts.get("interpret", True))
 
     def run(arrays, ids, valid):
         names = list(STREAMS) + ["cons", "dir", "valid"]
@@ -242,7 +241,7 @@ def _build_pallas_fused(caps_h, classes_key, fixed_len, fmt_name, kmer_k, opts):
         ) + (1,)
         fn = _build_fused_gather_decode(
             caps_h, classes_key, fixed_len, int(ids.shape[0]), shapes,
-            tuple(names), fmt_name, kmer_k, interpret,
+            tuple(names), fmt_name, kmer_k, interpret_mode(),
         )
         return fn(arrays, ids, valid)
 
@@ -335,9 +334,7 @@ def _build_pallas_unpack(widths, cap, nb, interpret):
     return run
 
 
-def sage_unpack_pallas(
-    packed, dicts, widths, *, interpret: bool = True
-) -> dict[str, jax.Array]:
+def sage_unpack_pallas(packed, dicts, widths) -> dict[str, jax.Array]:
     """Unpack codec extent payloads with the Pallas kernel.
 
     Same contract as :func:`repro.core.decode_jax.unpack_block_rows`
@@ -347,6 +344,6 @@ def sage_unpack_pallas(
     wt = tuple((s, int(wmap[s])) for s in STREAMS)
     packed = jnp.asarray(packed, dtype=jnp.uint32)
     nb, cap = packed.shape
-    run = _build_pallas_unpack(wt, cap, nb, interpret)
+    run = _build_pallas_unpack(wt, cap, nb, interpret_mode())
     out = run(packed, jnp.asarray(dicts, dtype=jnp.uint8)[: len(wt)])
     return {s: a for (s, _w), a in zip(wt, out)}

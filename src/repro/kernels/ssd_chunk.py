@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.mode import interpret_mode
+
 F32 = jnp.float32
 
 
@@ -43,7 +45,7 @@ def _ssd_intra_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, dec_ref
     dec_ref[0, 0] = total
 
 
-def ssd_intra_pallas(x, dt, a, B_, C_, *, interpret: bool = True):
+def ssd_intra_pallas(x, dt, a, B_, C_):
     """x: (B, nc, Q, H, P); dt, a: (B, nc, Q, H); B_, C_: (B, nc, Q, H, N).
 
     Returns (y_intra (B,nc,Q,H,P), chunk_state (B,nc,H,P,N), total (B,nc,H),
@@ -71,6 +73,6 @@ def ssd_intra_pallas(x, dt, a, B_, C_, *, interpret: bool = True):
             jax.ShapeDtypeStruct((Bb, nc, H, P, N), F32),
             jax.ShapeDtypeStruct((Bb, nc, H), F32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(x, dt, a, B_, C_)
     return y, st, tot

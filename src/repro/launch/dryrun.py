@@ -76,8 +76,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool, opts: TrainOptions, out_dir
         t_compile = time.time() - t1
     mem = compiled.memory_analysis()
     xla_cost = compiled.cost_analysis() or {}
-    if isinstance(xla_cost, (list, tuple)):  # jax 0.4.x returns [dict]
-        xla_cost = xla_cost[0] if xla_cost else {}
     cost = analyze(compiled.as_text())  # trip-count-aware walker
 
     flops_dev = float(cost.flops)

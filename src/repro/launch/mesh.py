@@ -18,14 +18,11 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    """Arbitrary mesh helper (tests, examples, elastic restarts).
-
-    ``axis_types`` only exists on newer jax; pass it when available so
-    explicit-sharding jax keeps treating these axes as Auto."""
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.6 explicit-sharding API
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+    """Arbitrary mesh helper (tests, examples, elastic restarts); every
+    axis is Auto, so these meshes keep sharding by GSPMD propagation."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 # TPU v5e hardware constants (per chip) used by the roofline analysis

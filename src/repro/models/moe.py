@@ -13,7 +13,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import shard_map
 from repro.models.layers import F32, _act, dense_init, mlp_apply, mlp_init
 
 
@@ -131,7 +130,7 @@ def moe_apply(p, x, cfg):
             yl = _dispatch_ffn(xl, te, tw, wg, wu, wd, cfg, off, e_local)
             return jax.lax.psum(yl, m)
 
-        y = shard_map(
+        y = jax.shard_map(
             local, mesh=rules.mesh,
             in_specs=(xspec, kspec, kspec, espec, espec, espec),
             out_specs=xspec, check_vma=False,

@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.core.decode_jax import bucket_size
 from repro.core.errors import SageIOError
+from repro.kernels.mode import DECODE, KMER, ONE_HOT, require_pallas
 from repro.serving.scheduler import RequestState, Scheduler, _Entry
 from repro.serving.session_pool import SessionPool
 
@@ -62,13 +63,17 @@ class ContinuousBatcher:
         max_batch_bytes: int = 64 << 20,
         max_union_blocks: int = 64,
         use_pallas: bool = False,
-        interpret: bool = True,
         prefetch_isp: bool = True,
     ) -> None:
         if max_batch_requests < 1:
             raise ValueError("max_batch_requests must be >= 1")
         if max_union_blocks < 1:
             raise ValueError("max_union_blocks must be >= 1")
+        if use_pallas:
+            # sessions are built lazily, per round; refuse here, at build
+            require_pallas(
+                [DECODE, KMER, ONE_HOT], "a ContinuousBatcher with use_pallas=True"
+            )
         self.pool = pool
         self.scheduler = scheduler
         self.engine = engine
@@ -76,7 +81,6 @@ class ContinuousBatcher:
         self.max_batch_bytes = max_batch_bytes
         self.max_union_blocks = max_union_blocks
         self.use_pallas = use_pallas
-        self.interpret = interpret
         self.prefetch_isp = prefetch_isp
         self.stats = {
             "rounds": 0, "fused_reads": 0, "fused_read_requests": 0,
@@ -90,7 +94,7 @@ class ContinuousBatcher:
 
     # ------------------------------------------------------------------ step
     def session(self):
-        return self.pool.session(use_pallas=self.use_pallas, interpret=self.interpret)
+        return self.pool.session(use_pallas=self.use_pallas)
 
     def _resolve(self, e: _Entry) -> np.ndarray:
         """Resolve (once) and cache the request's global block ids."""

@@ -201,7 +201,6 @@ class SageServer:
         max_batch_bytes: int = 64 << 20,
         max_union_blocks: int = 64,
         use_pallas: bool = False,
-        interpret: bool = True,
     ) -> None:
         if pool is not None and store is not None:
             raise ValueError("pass pool= or store=, not both")
@@ -216,7 +215,7 @@ class SageServer:
             max_batch_requests=max_batch_requests,
             max_batch_bytes=max_batch_bytes,
             max_union_blocks=max_union_blocks,
-            use_pallas=use_pallas, interpret=interpret,
+            use_pallas=use_pallas,
         )
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()

@@ -7,8 +7,8 @@ state, and N per-request stores would hold N copies of every hot block
 group — thrashing exactly the memory the LRU exists to protect.
 
 The pool owns ONE :class:`SageStore` and hands out shared
-:class:`SageReadSession` views keyed by decode path ``(use_pallas,
-interpret)`` — sessions are stateless views (store + flags), so any number
+:class:`SageReadSession` views keyed by decode path (``use_pallas``) —
+sessions are stateless views (store + flags), so any number
 of tenants can hold the same one. Hot datasets therefore stay resident
 once across every request that touches them, and the pool is the single
 place the serving frontend asks about residency (cache-aware admission),
@@ -41,19 +41,18 @@ class SessionPool:
                 f"pass store= or store kwargs {sorted(store_kwargs)}, not both"
             )
         self.store = store if store is not None else SageStore(**store_kwargs)
-        self._sessions: dict[tuple, SageReadSession] = {}
+        self._sessions: dict[bool, SageReadSession] = {}
         self._lock = threading.Lock()
         self.residency_score_errors = 0  # scoring failures, no longer silent
 
     # ------------------------------------------------------------- sessions
-    def session(self, *, use_pallas: bool = False, interpret: bool = True) -> SageReadSession:
+    def session(self, *, use_pallas: bool = False) -> SageReadSession:
         """The shared session for a decode path (created once per path)."""
-        key = (use_pallas, interpret)
         with self._lock:
-            s = self._sessions.get(key)
+            s = self._sessions.get(use_pallas)
             if s is None:
-                s = self.store.session(use_pallas=use_pallas, interpret=interpret)
-                self._sessions[key] = s
+                s = self.store.session(use_pallas=use_pallas)
+                self._sessions[use_pallas] = s
             return s
 
     @property

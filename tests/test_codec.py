@@ -267,7 +267,7 @@ def test_jit_and_pallas_decoders_match_host_reference():
     packed = _pad_payloads(words, starts, nwords)
     host = C.decode_blocks(packed, widths, dicts)
     jit = unpack_block_rows(packed, dicts, widths)
-    pal = sage_unpack_pallas(packed, dicts, widths, interpret=True)
+    pal = sage_unpack_pallas(packed, dicts, widths)
     for s in STREAMS:
         np.testing.assert_array_equal(host[s], np.asarray(jit[s]), err_msg=s)
         np.testing.assert_array_equal(host[s], np.asarray(pal[s]), err_msg=s)

@@ -91,8 +91,7 @@ def test_unregistered_format_falls_back_to_two_step(sources):
     session — via the legacy two-step path — and match a plain session."""
     sf, _ = sources
 
-    def apply_rc(tokens, *, kmer_k=None, use_pallas=False, interpret=True,
-                 n_tokens=None):
+    def apply_rc(tokens, *, kmer_k=None, use_pallas=False, n_tokens=None):
         return tokens[..., ::-1]
 
     register_format(FormatSpec("revtok", "revtok", apply_rc, doc="test-only"))

@@ -268,8 +268,7 @@ def phase_stream(store, c: dict) -> dict:
           f"device_bytes_all_blocks={nb * store.block_nbytes(NAME)} "
           f"live_device_bytes={resident}")
     print(f"info: first_stream_seconds={t1 - t0} (compiles included) "
-          f"second_stream_seconds={t3 - t2} bases_per_second={bases / (t3 - t2)} "
-          f"overlap_fraction={io['stream_overlap_fraction']}")
+          f"second_stream_seconds={t3 - t2} bases_per_second={bases / (t3 - t2)}")
     stages = ("io", "upload", "dispatch", "consume", "wall")
     print("info: second pass stage seconds "
           + " ".join(f"{k}={io[f'stream_{k}_seconds']}" for k in stages)

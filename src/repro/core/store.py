@@ -586,18 +586,6 @@ class SageStore:
         ``transfer_stats``. Snapshot; mutate via ``reset_io_stats``."""
         d = dict(self._io)
         d.update(self._extent_cache.stats)
-        stage = (
-            d.get("stream_io_seconds", 0.0)
-            + d.get("stream_upload_seconds", 0.0)
-            + d.get("stream_dispatch_seconds", 0.0)
-            + d.get("stream_consume_seconds", 0.0)
-        )
-        # overlap proof for the pipelined stream: 1 - wall/sum(stages) is 0
-        # for a fully serial pipeline and approaches 1 - 1/n_stages when
-        # every stage hides behind the slowest one
-        d["stream_overlap_fraction"] = (
-            1.0 - d.get("stream_wall_seconds", 0.0) / stage if stage > 0 else 0.0
-        )
         return d
 
     def reset_io_stats(self) -> None:
@@ -721,20 +709,21 @@ class SageStore:
             self._bump_cache(name, "misses")
             r = self._require_reader(name, gi)
             stride = self._group_stride()
-        if r.codec is not None:
-            entry = self._host_group_codec(name, gi, r)
-            db, decoded = self._decode_codec_entry(r, stride, entry)
-        else:
-            arrays = self._host_group_raw(name, gi, r, stride)
-            db = DeviceBlocks(
-                arrays=arrays,
-                caps=r.meta.caps,
-                classes=r.meta.classes,
-                fixed_len=r.meta.fixed_read_len,
-                n_blocks=stride,
-                on_device=False,
-            ).to_device(mesh=self.mesh)
-            decoded = 0
+        with jax.profiler.TraceAnnotation("sage.store.group_upload"):
+            if r.codec is not None:
+                entry = self._host_group_codec(name, gi, r)
+                db, decoded = self._decode_codec_entry(r, stride, entry)
+            else:
+                arrays = self._host_group_raw(name, gi, r, stride)
+                db = DeviceBlocks(
+                    arrays=arrays,
+                    caps=r.meta.caps,
+                    classes=r.meta.classes,
+                    fixed_len=r.meta.fixed_read_len,
+                    n_blocks=stride,
+                    on_device=False,
+                ).to_device(mesh=self.mesh)
+                decoded = 0
         with self._lock:
             # re-check under the lock: a concurrent thread may have uploaded
             # the same group (keep its entry) or quarantined it (discard ours)
@@ -1005,24 +994,25 @@ class SageStore:
             return dbs[gis[0]], ids % g
         # stable group-sort, gather each group's requested rows once, and
         # invert the permutation — all index math vectorized on host
-        sidx = np.argsort(gids, kind="stable")
-        sorted_ids, sorted_gids = ids[sidx], gids[sidx]
-        parts = [
-            {
-                k: v[jnp.asarray(sorted_ids[sorted_gids == gi] % g, jnp.int32)]
-                for k, v in dbs[gi].arrays.items()
-            }
-            for gi in gis
-        ]
-        arrays = {k: jnp.concatenate([p[k] for p in parts], axis=0) for k in parts[0]}
-        local = np.empty(ids.size, dtype=np.int64)
-        local[sidx] = np.arange(ids.size, dtype=np.int64)
-        first = dbs[gis[0]]
-        db = DeviceBlocks(
-            arrays=arrays, caps=first.caps, classes=first.classes,
-            fixed_len=first.fixed_len, n_blocks=ids.size,
-            on_device=True, mesh=self.mesh,
-        )
+        with jax.profiler.TraceAnnotation("sage.store.gather"):
+            sidx = np.argsort(gids, kind="stable")
+            sorted_ids, sorted_gids = ids[sidx], gids[sidx]
+            parts = [
+                {
+                    k: v[jnp.asarray(sorted_ids[sorted_gids == gi] % g, jnp.int32)]
+                    for k, v in dbs[gi].arrays.items()
+                }
+                for gi in gis
+            ]
+            arrays = {k: jnp.concatenate([p[k] for p in parts], axis=0) for k in parts[0]}
+            local = np.empty(ids.size, dtype=np.int64)
+            local[sidx] = np.arange(ids.size, dtype=np.int64)
+            first = dbs[gis[0]]
+            db = DeviceBlocks(
+                arrays=arrays, caps=first.caps, classes=first.classes,
+                fixed_len=first.fixed_len, n_blocks=ids.size,
+                on_device=True, mesh=self.mesh,
+            )
         return db, local
 
     def n_blocks(self, name: str) -> int:
@@ -1210,33 +1200,35 @@ class SageReadSession:
         ``fused`` sessions run gather+decode+format as ONE dispatch when a
         fuser is registered for ``fmt`` (bit-identical to the two-step
         path); mesh sessions and unfused formats take the two-step path."""
-        spec = get_format(fmt)
-        if self.fused and self.mesh is None and fused_format_supported(spec.name):
-            if spec.requires_k and kmer_k is None:
-                # the same contract apply_format enforces on the 2-step path
-                raise ValueError(
-                    f"SAGe_Read({name!r}): format {spec.name!r} requires kmer_k "
-                    f"(registered formats: {available_formats()})"
+        with jax.profiler.TraceAnnotation("sage.read.decode"):
+            spec = get_format(fmt)
+            if self.fused and self.mesh is None and fused_format_supported(spec.name):
+                if spec.requires_k and kmer_k is None:
+                    # the same contract apply_format enforces on the 2-step path
+                    raise ValueError(
+                        f"SAGe_Read({name!r}): format {spec.name!r} requires kmer_k "
+                        f"(registered formats: {available_formats()})"
+                    )
+                path_key = ("pallas", ()) if self.use_pallas else ("vmap", ())
+                if self.use_pallas:
+                    import repro.kernels.sage_decode  # noqa: F401  (registers "pallas")
+                return fused_decode_blocks_bucketed(
+                    db, local, fmt_name=spec.name, kmer_k=kmer_k, path_key=path_key,
                 )
-            path_key = ("pallas", ()) if self.use_pallas else ("vmap", ())
-            if self.use_pallas:
-                import repro.kernels.sage_decode  # noqa: F401  (registers "pallas")
-            return fused_decode_blocks_bucketed(
-                db, local, fmt_name=spec.name, kmer_k=kmer_k, path_key=path_key,
+            path = (
+                dict(mesh=self.mesh, decoder_key=self._decoder_key())
+                if self.mesh is not None
+                else dict(decoder=self._decoder(db))
             )
-        path = (
-            dict(mesh=self.mesh, decoder_key=self._decoder_key())
-            if self.mesh is not None
-            else dict(decoder=self._decoder(db))
-        )
-        return decode_blocks_bucketed(
-            db, local,
-            postprocess=lambda dec: apply_format(
-                dec, fmt, kmer_k=kmer_k, use_pallas=self.use_pallas,
-                context=f"SAGe_Read({name!r})",
-            ),
-            **path,
-        )
+
+            def postprocess(dec):
+                with jax.profiler.TraceAnnotation("sage.read.format"):
+                    return apply_format(
+                        dec, fmt, kmer_k=kmer_k, use_pallas=self.use_pallas,
+                        context=f"SAGe_Read({name!r})",
+                    )
+
+            return decode_blocks_bucketed(db, local, postprocess=postprocess, **path)
 
     # -------------------------------------------------------------- SAGe_ISP
     def read_stream(
@@ -1276,8 +1268,8 @@ class SageReadSession:
         background I/O stage ranged-reads group i+2's extents into the host
         cache while group i+1 uploads and group i's decode runs — dispatch
         depth ``dispatch`` (default 2), I/O readahead ``readahead`` fetches
-        beyond that, double-buffered device slots, per-stage wall-time and
-        ``overlap_fraction`` accounting folded into ``store.io_stats``.
+        beyond that, double-buffered device slots, per-stage wall-time
+        accounting folded into ``store.io_stats``.
         Other ``mode`` values: ``"sync"``, ``"prefetch"``, ``"dispatch"``
         name the legacy paths explicitly; ``None`` (default) infers from
         ``dispatch``/``prefetch`` exactly as before.

@@ -28,11 +28,11 @@ the oldest retired slot's groups are released (``store.release_group`` —
 host cache keeps the bytes), so steady-state streaming holds a bounded
 group set and never churns the store's shared LRU.
 
-Accounting: per-stage wall seconds, fetch counts, in-flight high-water
-marks, and ``overlap_fraction = 1 - wall / sum(stage)`` — 0 when the
-pipeline degenerates to sequential, approaching ``1 - 1/n_stages`` when
-every stage hides behind the slowest. Stats fold into ``store.io_stats``
-(``stream_*`` keys) on close/exhaustion.
+Accounting: per-stage wall seconds, fetch counts and in-flight high-water
+marks; stats fold into ``store.io_stats`` (``stream_*`` keys) on
+close/exhaustion. The io stage's clock runs on the worker thread, alongside
+the consumer; the time the consumer blocks on the I/O stage's queue is the
+``sage.stream.io_wait`` profiler span.
 """
 
 from __future__ import annotations
@@ -43,14 +43,16 @@ import time
 from collections import deque
 from typing import Iterator, Optional
 
+import jax
+
 from repro.core.store import SageReadSession, StreamBatch
 
 _PUT_TIMEOUT = 0.1  # bounded queue puts poll the stop flag at this period
 
 
 class StreamStats:
-    """Per-stream overlap accounting (see module docstring for the stage
-    definitions). ``overlap_fraction`` is the proof the phases overlap."""
+    """Per-stream stage accounting (see module docstring for the stage
+    definitions)."""
 
     _FIELDS = (
         "io_seconds", "upload_seconds", "dispatch_seconds", "consume_seconds",
@@ -71,18 +73,8 @@ class StreamStats:
         self.slot_releases = 0
         self._lock = threading.Lock()  # io thread and consumer both write
 
-    @property
-    def overlap_fraction(self) -> float:
-        stage = (
-            self.io_seconds + self.upload_seconds
-            + self.dispatch_seconds + self.consume_seconds
-        )
-        return 1.0 - self.wall_seconds / stage if stage > 0 else 0.0
-
     def to_dict(self) -> dict:
-        d = {k: getattr(self, k) for k in self._FIELDS}
-        d["overlap_fraction"] = self.overlap_fraction
-        return d
+        return {k: getattr(self, k) for k in self._FIELDS}
 
 
 class _StreamState:
@@ -216,15 +208,16 @@ class PipelinedStream:
         the worker forwards every exception — but a hang here would be
         strictly worse than a loud error)."""
         st = self._state
-        while True:
-            try:
-                return st.ready.get(timeout=0.2)
-            except queue.Empty:
-                if not self._thread.is_alive() and st.ready.empty():
-                    raise RuntimeError(
-                        f"pipelined stream on {self.name!r}: I/O worker died "
-                        f"without reporting"
-                    ) from None
+        with jax.profiler.TraceAnnotation("sage.stream.io_wait"):
+            while True:
+                try:
+                    return st.ready.get(timeout=0.2)
+                except queue.Empty:
+                    if not self._thread.is_alive() and st.ready.empty():
+                        raise RuntimeError(
+                            f"pipelined stream on {self.name!r}: I/O worker died "
+                            f"without reporting"
+                        ) from None
 
     def _run(self) -> Iterator[StreamBatch]:
         st = self._state

@@ -148,7 +148,7 @@ class SageTokenPipeline:
 
     @property
     def stream_stats(self) -> dict:
-        """Per-stage wall time and overlap accounting of the *open* pipelined
+        """Per-stage wall time and fetch accounting of the *open* pipelined
         ISP stream (empty in ``dispatch`` mode / before the first fetch).
         Closed streams fold the same numbers into ``io_stats['stream_*']``."""
         from repro.core.streaming import PipelinedStream
@@ -169,8 +169,6 @@ class SageTokenPipeline:
         if hasattr(stream, "stats"):
             ts = self.transfer_stats
             for k, v in stream.stats.to_dict().items():
-                if k == "overlap_fraction":
-                    continue  # a ratio; per-stream value lives in stream_stats
                 key = f"stream_{k}"
                 if k.endswith("hwm"):
                     ts[key] = max(ts.get(key, 0), v)

@@ -27,7 +27,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 from collections import Counter
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -598,6 +598,38 @@ def _gather_blocks_jit(arrays, ids, valid):
 def gather_block_arrays(db: DeviceBlocks, ids: np.ndarray, valid: np.ndarray) -> dict[str, jax.Array]:
     """Gather a padded block-id set out of prepared arrays, on device."""
     return _gather_blocks_jit(db.arrays, jnp.asarray(ids, jnp.int32), jnp.asarray(valid, jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("mesh",))
+def _gather_group_rows_jit(groups, where, mesh):
+    """Rows out of several resident block groups, in request order: every
+    group gives the same rows (``where[1]``) and ``where[0]`` picks each
+    lane's group, so only O(groups x lanes) rows move on device."""
+    TRACE_COUNTS["group_gather"] += 1
+    slot, row = where[0], where[1]
+    out = {}
+    for k in groups[0]:
+        picked = groups[0][k][row]
+        lane = (-1,) + (1,) * (picked.ndim - 1)
+        for s in range(1, len(groups)):
+            picked = jnp.where((slot == s).reshape(lane), groups[s][k][row], picked)
+        out[k] = picked
+    if mesh is not None:
+        out = jax.lax.with_sharding_constraint(out, block_specs(out, mesh))
+    return out
+
+
+def gather_group_rows(
+    groups: Sequence[dict[str, jax.Array]],
+    slot: np.ndarray,
+    row: np.ndarray,
+    mesh: Optional[Mesh] = None,
+) -> dict[str, jax.Array]:
+    """One dispatch gathering lane ``i`` from row ``row[i]`` of resident group
+    ``groups[slot[i]]`` for every array; block-sharded on ``mesh`` when given
+    (the lane count must then divide evenly over its shards)."""
+    where = np.stack([slot, row]).astype(np.int32)
+    return _gather_group_rows_jit(tuple(groups), where, mesh)
 
 
 def _fill_counts(out: dict[str, jax.Array], sub: dict[str, jax.Array]) -> dict[str, jax.Array]:

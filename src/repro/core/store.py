@@ -48,7 +48,9 @@ from repro.core.decode_jax import (
     decode_blocks_bucketed,
     fused_decode_blocks_bucketed,
     fused_format_supported,
+    gather_group_rows,
     localize_directory,
+    pad_block_ids,
     prepare_device_blocks,
     unpack_block_rows,
 )
@@ -174,6 +176,7 @@ class SageStore:
         self._io = new_io_stats()
         self._io["group_uploads"] = 0
         self._io["stale_retries"] = 0
+        self._io["cross_group_gathers"] = 0
         for k in (
             "stream_fetches", "stream_io_groups", "stream_slot_releases",
             "stream_inflight_hwm", "stream_slot_hwm",
@@ -682,8 +685,8 @@ class SageStore:
 
     def _group_stride(self) -> int:
         """Device rows per resident block group: ``group_blocks`` padded up
-        to the mesh shard count so every group shards evenly and group
-        concatenation keeps a uniform row stride."""
+        to the mesh shard count so every group shards evenly and all groups,
+        the ragged tail too, share one shape for the cross-group gather."""
         g = self.group_blocks
         return g + (-g) % block_shard_count(self.mesh)
 
@@ -951,11 +954,16 @@ class SageStore:
         Eager sources return the whole-file residency with ``ids``
         unchanged. Lazy (v2) sources resolve the covering block groups and
         make each device-resident independently (``(name, group)`` LRU
-        entries). A single covering group is returned as-is; a multi-group
-        request gathers only the REQUESTED rows out of each resident group
-        and concatenates those (device-side ops, O(len(ids)) rows copied —
-        never whole groups; no host transfer). Only the covering groups'
-        extent bytes ever leave disk.
+        entries). A single covering group is returned as-is with local rows
+        ``ids % group_blocks``. A multi-group request is ONE jitted dispatch
+        that gathers only the requested rows out of the resident groups, in
+        request order (O(groups x len(ids)) rows moved on device — never
+        whole groups; no host transfer); ``io_stats["cross_group_gathers"]``
+        counts them. Its ids pad to the decoder's bucket (per shard under a
+        store mesh, see :func:`pad_block_ids`), so the returned residency
+        holds the bucket's rows and the local rows are ``arange(len(ids))``,
+        the first of them. Only the covering groups' extent bytes ever leave
+        disk.
 
         A concurrent ``register()`` can invalidate the reader this read
         planned against mid-flight; that race is retried ONCE here (the
@@ -992,28 +1000,24 @@ class SageStore:
         dbs = {gi: self._prepared_group(name, gi) for gi in gis}
         if len(gis) == 1:
             return dbs[gis[0]], ids % g
-        # stable group-sort, gather each group's requested rows once, and
-        # invert the permutation — all index math vectorized on host
+        with self._lock:
+            self._io["cross_group_gathers"] += 1
+        # one dispatch gathers the requested rows in request order; ids pad
+        # to the decoder's own bucket, so it compiles once per (groups,
+        # bucket) and a sharded residency stays evenly divisible
         with jax.profiler.TraceAnnotation("sage.store.gather"):
-            sidx = np.argsort(gids, kind="stable")
-            sorted_ids, sorted_gids = ids[sidx], gids[sidx]
-            parts = [
-                {
-                    k: v[jnp.asarray(sorted_ids[sorted_gids == gi] % g, jnp.int32)]
-                    for k, v in dbs[gi].arrays.items()
-                }
-                for gi in gis
-            ]
-            arrays = {k: jnp.concatenate([p[k] for p in parts], axis=0) for k in parts[0]}
-            local = np.empty(ids.size, dtype=np.int64)
-            local[sidx] = np.arange(ids.size, dtype=np.int64)
+            padded, _ = pad_block_ids(ids, block_shard_count(self.mesh))
+            slot = np.searchsorted(gis, padded // g)
+            arrays = gather_group_rows(
+                [dbs[gi].arrays for gi in gis], slot, padded % g, self.mesh
+            )
             first = dbs[gis[0]]
             db = DeviceBlocks(
                 arrays=arrays, caps=first.caps, classes=first.classes,
-                fixed_len=first.fixed_len, n_blocks=ids.size,
+                fixed_len=first.fixed_len, n_blocks=padded.size,
                 on_device=True, mesh=self.mesh,
             )
-        return db, local
+        return db, np.arange(ids.size, dtype=np.int64)
 
     def n_blocks(self, name: str) -> int:
         return self.meta(name).n_blocks

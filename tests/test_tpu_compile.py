@@ -7,7 +7,7 @@ bucket sizes 8 and 64. A compile that passes here is not a chip run; it
 proves only that the chip's compiler accepts the program and that it fits.
 
 The XLA programs (vmap decode, fused vmap decode + k-mer format, codec
-unpack) must compile. Every Pallas kernel is a strict xfail that records the
+unpack, the store's cross-group gather) must compile. Every Pallas kernel is a strict xfail that records the
 reason Mosaic refuses it today; a kernel that starts to compile turns its
 xfail into an XPASS, which fails the suite until the test becomes a pass.
 Only one-hot compiles, and only at one block per call.
@@ -28,6 +28,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.decode_jax import (
     DeviceBlocks,
     _HashableCaps,
+    _gather_group_rows_jit,
     decode_blocks_bucketed,
     fused_decode_blocks_bucketed,
     prepare_block_arrays,
@@ -149,6 +150,16 @@ def test_codec_unpack_compiles(corpus, one_chip, bucket):
         _spec((bucket, r._cap_words), np.uint32, one_chip),
         _spec(np.shape(r._codec_dicts), np.uint8, one_chip),
     ).compile()
+    _check_fits(compiled)
+
+
+@pytest.mark.parametrize("n_groups,bucket", [(2, 4), (3, 8)])
+def test_cross_group_gather_compiles(corpus, one_chip, n_groups, bucket):
+    """The store's one-dispatch gather across resident 8-block groups."""
+    sf, _ = corpus
+    groups = tuple(_resident_specs(sf, one_chip, rows=8) for _ in range(n_groups))
+    where = _spec((2, bucket), np.int32, one_chip)
+    compiled = _gather_group_rows_jit.lower(groups, where, mesh=None).compile()
     _check_fits(compiled)
 
 

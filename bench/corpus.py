@@ -2,10 +2,12 @@
 the codec v2 container the store under test serves.
 
 The read generator is a copy of the program's ``genomics/synth.py``
-(reference with dispersed repeats, a donor with clustered SNPs, reads with
-the sequencing profile's substitutions, indels, bursts and N dropouts), so
-the benchmark's inputs do not move when the program's generator does. Only
-the short-read profiles the configurations name are kept. The reads are the
+(reference with dispersed repeats, a donor with clustered SNPs, reads of the
+sequencing profile's length distribution with its substitutions, indels,
+bursts, N dropouts and chimeras), so the benchmark's inputs do not move when
+the program's generator does. The three profiles are the sequencing
+technologies of the SAGe paper's read sets (arXiv:2504.03732, Table 3):
+Illumina short reads, PacBio HiFi and ONT long reads. The reads are the
 ground truth the correctness check compares served output against.
 """
 
@@ -18,13 +20,21 @@ from pathlib import Path
 
 import numpy as np
 
-# sequencing profiles (genomics/synth.py PROFILES): read length, rates of
-# substitution/insertion/deletion, geometric indel length, N dropouts,
-# chimeras, error bursts
+# sequencing profiles (genomics/synth.py PROFILES, and its _qual_for's
+# quality means): read length mean and sd, rates of substitution, insertion
+# and deletion, geometric indel length, N dropouts, chimeras, error bursts,
+# the ReadSet kind and the phred quality mean
 PROFILES = {
-    "illumina": dict(read_len=150, sub_rate=0.001, ins_rate=0.0001, del_rate=0.0001,
-                     indel_len_p=0.7, n_rate=0.0015, chimera_rate=0.0005,
-                     burst_rate=0.002, burst_len=10, burst_sub_rate=0.15),
+    "illumina": dict(read_len=150, read_len_sd=0, sub_rate=0.001, ins_rate=0.0001,
+                     del_rate=0.0001, indel_len_p=0.7, n_rate=0.0015, chimera_rate=0.0005,
+                     burst_rate=0.002, burst_len=10, burst_sub_rate=0.15, kind="short",
+                     qual=38),
+    "hifi": dict(read_len=12000, read_len_sd=2500, sub_rate=0.004, ins_rate=0.003,
+                 del_rate=0.003, indel_len_p=0.55, n_rate=0.001, chimera_rate=0.01,
+                 burst_rate=0.0005, burst_len=20, burst_sub_rate=0.2, kind="long", qual=30),
+    "ont": dict(read_len=8000, read_len_sd=3000, sub_rate=0.03, ins_rate=0.025,
+                del_rate=0.025, indel_len_p=0.45, n_rate=0.002, chimera_rate=0.02,
+                burst_rate=0.001, burst_len=30, burst_sub_rate=0.35, kind="long", qual=14),
 }
 
 
@@ -93,20 +103,29 @@ def _apply_errors(seq: np.ndarray, p: dict, rng: np.random.Generator) -> np.ndar
     return res
 
 
-def sample_reads(ref: np.ndarray, profile: str, depth: float, seed: int,
-                 snp_rate: float) -> tuple[list[np.ndarray], list[np.ndarray], np.ndarray]:
-    """Reads (coded 0..4), phred+33 qualities and the reference position each
-    read was sampled at, at ``depth`` over ``ref``."""
+def sample_reads(ref: np.ndarray, profile: str, depth: float, seed: int, snp_rate: float
+                 ) -> tuple[list[np.ndarray], list[np.ndarray], np.ndarray, np.ndarray]:
+    """Reads (coded 0..4), phred+33 qualities, the reference position each
+    read was sampled at, and which reads are chimeric (two segments from
+    different loci, placed at the first), at ``depth`` over ``ref``."""
     p = PROFILES[profile]
     rng = np.random.default_rng(seed)
     donor = _donor(ref, rng, snp_rate)
-    reads, quals, positions, got = [], [], [], 0
-    L = min(p["read_len"], ref.size - 1)
+    reads, quals, positions, chimeric, got = [], [], [], [], 0
     while got < int(ref.size * depth):
-        # a chimera needs L >= 400, so short reads draw and never take one
-        rng.random()
-        pos = int(rng.integers(0, ref.size - L))
-        frag = donor[pos: pos + L]
+        L = p["read_len"] if p["read_len_sd"] == 0 else int(
+            np.clip(rng.normal(p["read_len"], p["read_len_sd"]), 200, 4 * p["read_len"]))
+        L = min(L, ref.size - 1)
+        # the chimera draw is made for every read: short reads never take one
+        chimera = rng.random() < p["chimera_rate"] and L >= 400
+        if chimera:
+            l1 = int(rng.integers(L // 4, 3 * L // 4))
+            pos = int(rng.integers(0, ref.size - l1))
+            p2 = int(rng.integers(0, ref.size - (L - l1)))
+            frag = np.concatenate([donor[pos: pos + l1], donor[p2: p2 + (L - l1)]])
+        else:
+            pos = int(rng.integers(0, ref.size - L))
+            frag = donor[pos: pos + L]
         if rng.random() < 0.5:
             frag = revcomp(frag)
         read = _apply_errors(frag, p, rng)
@@ -114,9 +133,10 @@ def sample_reads(ref: np.ndarray, profile: str, depth: float, seed: int,
             continue
         reads.append(read)
         positions.append(pos)
-        quals.append(np.clip(rng.normal(38, 3, read.size), 2, 41).astype(np.uint8) + 33)
+        chimeric.append(chimera)
+        quals.append(np.clip(rng.normal(p["qual"], 3, read.size), 2, 41).astype(np.uint8) + 33)
         got += read.size
-    return reads, quals, np.asarray(positions, np.int64)
+    return reads, quals, np.asarray(positions, np.int64), np.asarray(chimeric, bool)
 
 
 @dataclasses.dataclass
@@ -125,6 +145,8 @@ class Corpus:
     path: Path  # the codec v2 container
     reads: list  # ground truth, coded uint8 arrays
     positions: np.ndarray  # the reference position each read was sampled at
+    chimeric: np.ndarray  # which reads join two loci (placed at the first)
+    kind: str  # "short" or "long" reads
     token_target: int  # the encoder's tokens per block
     bases: int
     container_bytes: int
@@ -147,12 +169,16 @@ def build(config: dict, workdir: Path) -> Corpus:
 
     c = config["corpus"]
     g = config["guarantees"]
+    p = PROFILES[c["profile"]]
+    if c["read_length"] != p["read_len"]:
+        raise ValueError(f"{config['name']}: corpus.read_length {c['read_length']} is not "
+                         f"the {c['profile']} profile's mean read length {p['read_len']}")
     t0 = time.perf_counter()
     ref = make_reference(c["reference_length"], c["seed"])
-    reads, quals, positions = sample_reads(ref, c["profile"], c["depth"], c["seed"] + 1,
-                                           c["snp_rate"])
+    reads, quals, positions, chimeric = sample_reads(ref, c["profile"], c["depth"],
+                                                     c["seed"] + 1, c["snp_rate"])
     t1 = time.perf_counter()
-    rs = ReadSet(reads=reads, quals=quals, kind="short", profile=c["profile"])
+    rs = ReadSet(reads=reads, quals=quals, kind=p["kind"], profile=c["profile"])
     sf = SageEncoder(ref, token_target=c["token_target"]).encode(rs)
     t2 = time.perf_counter()
     path = workdir / f"{config['name']}.sage2"
@@ -160,7 +186,8 @@ def build(config: dict, workdir: Path) -> Corpus:
              parity_group=g["parity_group"], codec=g["codec"])
     t3 = time.perf_counter()
     return Corpus(
-        name=config["name"], path=path, reads=reads, positions=positions,
+        name=config["name"], path=path, reads=reads, positions=positions, chimeric=chimeric,
+        kind=p["kind"],
         token_target=c["token_target"],
         bases=int(sum(r.size for r in reads)),
         container_bytes=os.path.getsize(path), n_blocks=sf.meta.n_blocks,

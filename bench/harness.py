@@ -161,7 +161,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, manifest: dic
         answers = run.close()
         obs = run.obs
         t_check = time.perf_counter()
-        truth = (corpus.reads, corpus.n_blocks, corpus.positions, corpus.token_target)
+        truth = (corpus.reads, corpus.n_blocks, corpus.positions, corpus.token_target,
+                 corpus.kind, corpus.chimeric)
         checks = oracle.check(answers, *truth)
         if control:  # the sound reading of this window, then the control's
             sound = checks

@@ -19,10 +19,18 @@ order of their mapped position, a block closed once it holds the token
 target. The generator knows where it sampled each read, so the same cut over
 the sampled positions places every block on the reference without the
 program: ``block_offset_spans`` is the largest distance, over the blocks
-served, between the median sampled position of a block's reads and of the
-reads that cut puts in it, in blocks' spans of reference. Reads mapped to a
-repeat's other copy or escaped to the end move a block's median by a fraction
-of a span; a block answered with its neighbour's reads moves it by about one.
+served, between the median place of a block's reads and of the reads that
+cut puts in it, in blocks' spans. A block answered with its neighbour's reads
+moves it by about one.
+
+Short reads are placed by sampled position. Reads mapped to a repeat's other
+copy or escaped to the end move a block of some 430 of them by a fraction of
+a span. A block of long reads holds a few, so a read placed elsewhere than
+its sampled position moves the blocks after it by a sizeable share of a
+span, and long reads start densely near the reference's start and sparsely
+near its end. So long-read blocks are placed by rank (``rank_offsets``),
+leaving out the reads the program places itself: chimeras, reads holding an
+N, and reads served out of order in their block.
 
 Every other number compared is a count of faults, and its limit is 0.
 """
@@ -100,9 +108,72 @@ def block_places(reads: list, positions, token_target: int) -> tuple[np.ndarray,
     return medians, float(np.median(np.diff(medians))) if n else 1.0
 
 
-def check(answers: list, reads: list, n_blocks: int, positions, token_target: int) -> dict:
+def rank_offsets(served: list, reads: list, n_blocks: int, positions, chimeric=None) -> float:
+    """``block_offset_spans`` of long-read blocks ``served`` (block id,
+    indices of the set's reads it holds, in row order), placed by rank.
+
+    The reads the oracle vouches for are ranked by sampled position. Block
+    ``b`` should hold the ranks after those of the blocks before it, as
+    many as it holds: its median rank is compared with that window's, in
+    spans of the median block's count. The program places some reads
+    itself, and the oracle does not vouch for them: chimeras (two loci),
+    reads holding an N (SAGe's corner case), and reads that lie before a
+    read ahead of them in their block (escapes, which the encoder writes
+    after the mapped reads). An escape that opens a block is not seen: it
+    moves the windows after its rank by one, and the last block, where
+    escapes go, leaves the ranks below its window out of its median. A
+    block is placed once every block before it has been served."""
+    vouched = np.array([not (r == PAD).any() for r in reads], bool)
+    if chimeric is not None:
+        vouched &= ~np.asarray(chimeric, bool)
+    rows: dict = {}
+    for b, held in served:
+        rows.setdefault(b, held)
+    for held in rows.values():
+        ahead = -1
+        for i in [i for i in held if vouched[i]]:
+            vouched[i] = positions[i] >= ahead
+            ahead = max(ahead, positions[i])
+    ids = np.flatnonzero(vouched)
+    rank = np.full(len(reads), -1, np.int64)
+    rank[ids[np.argsort(positions[ids], kind="stable")]] = np.arange(ids.size)
+    counts = {b: sum(bool(vouched[i]) for i in held) for b, held in rows.items()}
+    span = float(np.median([c for c in counts.values() if c] or [1]))
+    worst = 0
+    for b, held in served:
+        if any(c not in counts for c in range(b)):
+            continue
+        start = sum(counts[c] for c in range(b))
+        at = [rank[i] for i in held if vouched[i]]
+        if b == n_blocks - 1:
+            at = [r for r in at if r >= start]
+        if at:
+            worst = max(worst, float(abs(np.median(at) - start - (len(at) - 1) / 2) / span))
+    return worst
+
+
+def offset_spans(served: list, reads: list, n_blocks: int, positions, token_target: int,
+                 kind: str, chimeric=None) -> float:
+    """``block_offset_spans`` of the blocks ``served``: (block id, indices
+    of the set's reads it holds, in row order)."""
+    if kind == "long":
+        return rank_offsets(served, reads, n_blocks, positions, chimeric)
+    medians, span = block_places(reads, positions, token_target)
+    worst = 0
+    for b, held in served:
+        at = [positions[i] for i in held]
+        if at:
+            off = abs(np.median(at) - medians[min(b, medians.size - 1)]) / span
+            worst = max(worst, float(off))
+    return worst
+
+
+def check(answers: list, reads: list, n_blocks: int, positions, token_target: int,
+          kind: str = "short", chimeric=None) -> dict:
     """Count every fault in ``answers`` against the generated ``reads``,
-    sampled at ``positions`` and blocked at ``token_target`` tokens.
+    sampled at ``positions`` and blocked at ``token_target`` tokens: a
+    ``kind`` of ``"short"`` or ``"long"`` reads, ``chimeric`` marking the
+    reads that join two loci.
 
     Each answer is a dict with ``want`` (the block ids asked for), ``fmt``
     and ``kmer_k``, and either ``error`` (it failed or never came) or
@@ -110,12 +181,12 @@ def check(answers: list, reads: list, n_blocks: int, positions, token_target: in
     ``missing_reads`` is counted only when the answers cover all
     ``n_blocks`` blocks."""
     want_reads = Counter(bytes(r) for r in reads)
-    sampled_at: dict = {}
-    for r, p in zip(reads, np.asarray(positions).tolist()):
-        sampled_at.setdefault(bytes(r), p)
-    medians, span = block_places(reads, positions, token_target)
+    index: dict = {}
+    for i, r in enumerate(reads):
+        index.setdefault(bytes(r), i)
     out = dict.fromkeys(LIMITS, 0)
     content: dict = {}  # block id -> sorted tuple of its reads
+    served = []  # (block id, indices of the set's reads it holds, in row order)
     for a in answers:
         if a.get("error") is not None:
             out["unanswered"] += 1
@@ -136,10 +207,7 @@ def check(answers: list, reads: list, n_blocks: int, positions, token_target: in
             held = [toks[s: s + ln].astype(np.uint8).tobytes()
                     for s, ln in zip(starts[:n].tolist(), lens[:n].tolist())]
             out["wrong_reads"] += sum(r not in want_reads for r in held)
-            at = [sampled_at[r] for r in held if r in sampled_at]
-            if at:
-                off = abs(np.median(at) - medians[min(b, medians.size - 1)]) / span
-                out["block_offset_spans"] = max(out["block_offset_spans"], float(off))
+            served.append((b, [index[r] for r in held if r in index]))
             want = format_array(toks, fmt, a["kmer_k"], n_tok)
             got = d.get(FORMAT_KEY[fmt])
             if got is None or not np.array_equal(np.asarray(got[j]).astype(want.dtype), want):
@@ -147,13 +215,15 @@ def check(answers: list, reads: list, n_blocks: int, positions, token_target: in
             block = tuple(sorted(held))
             if content.setdefault(b, block) != block:
                 out["inconsistent_blocks"] += 1
-    served = Counter()
+    out["block_offset_spans"] = offset_spans(served, reads, n_blocks, np.asarray(positions),
+                                             token_target, kind, chimeric)
+    in_blocks = Counter()
     for block in content.values():
-        served.update(block)
-    out["excess_reads"] = sum(max(0, c - want_reads[r]) for r, c in served.items()
+        in_blocks.update(block)
+    out["excess_reads"] = sum(max(0, c - want_reads[r]) for r, c in in_blocks.items()
                               if r in want_reads)
     if len(content) == n_blocks:
-        out["missing_reads"] = sum(max(0, c - served[r]) for r, c in want_reads.items())
+        out["missing_reads"] = sum(max(0, c - in_blocks[r]) for r, c in want_reads.items())
     else:
         del out["missing_reads"]
     return out

@@ -21,8 +21,8 @@ AT = [0, 10, 20, 30, 40]  # where each read was sampled
 TARGET = 11  # tokens per block: reads 0+1 and 2+3 reach it, read 4 is left
 
 
-def block(reads):
-    toks = np.full(C, oracle.PAD, np.int8)
+def block(reads, width=C):
+    toks = np.full(width, oracle.PAD, np.int8)
     lens = np.zeros(R, np.int32)
     starts = np.zeros(R, np.int32)
     at = 0
@@ -144,3 +144,66 @@ def test_block_answered_with_its_neighbour_is_misplaced():
     checks = check([a], READS, n_blocks=3)
     assert checks["block_offset_spans"] == 20 / 17.5 and not oracle.passed(checks)
     assert sum(v for k, v in checks.items() if k != "block_offset_spans") == 0
+
+
+# Long reads: nine reads of 10 tokens sampled at 0, 10, ..., 80, blocks of
+# two. Read 3 is a chimera the encoder maps at its other locus (75), read 5
+# holds an N and is escaped to the end of the corpus.
+LONG = [np.random.default_rng(i).integers(0, 4, 10).astype(np.uint8) for i in range(9)]
+LONG[5][4] = oracle.PAD
+LONG_AT = np.array([10 * i for i in range(9)])
+CHIMERIC = np.arange(9) == 3
+LONG_BLOCKS = [[0, 1], [2, 4], [6, 7], [3, 8], [5]]  # the encoder's cut
+
+
+def long_answer(ids, contents=None):
+    contents = ids if contents is None else contents
+    rows = [block([LONG[i] for i in LONG_BLOCKS[c]], 32) for c in contents]
+    data = {key: np.stack([np.asarray(r[key]) for r in rows]) for key in rows[0]}
+    return {"want": np.array(ids), "block_ids": np.array(ids), "data": data,
+            "fmt": "2bit", "kmer_k": None}
+
+
+def long_check(answers, kind="long", chimeric=CHIMERIC):
+    return oracle.check(answers, LONG, 5, LONG_AT, 20, kind, chimeric)
+
+
+def test_long_read_sound_blocks_pass():
+    checks = long_check([long_answer([0, 1, 2, 3]), long_answer([4])])
+    assert checks == dict.fromkeys(oracle.LIMITS, 0) and oracle.passed(checks)
+    # placed by sampled position, as short reads are, the chimera and the
+    # escaped read move the cut after them: a block of two reads in two
+    short = long_check([long_answer([0, 1, 2, 3]), long_answer([4])], kind="short")
+    assert short["block_offset_spans"] == 1.5 and not oracle.passed(short)
+
+
+def test_long_read_chimera_not_vouched_for():
+    # ranked at its sampled position, the chimera misplaces block 3
+    checks = long_check([long_answer([0, 1, 2, 3]), long_answer([4])], chimeric=None)
+    assert checks["block_offset_spans"] == 0.75 and not oracle.passed(checks)
+
+
+def offsets(served, n_blocks=5):
+    return oracle.rank_offsets(served, LONG, n_blocks, LONG_AT, CHIMERIC)
+
+
+def test_long_read_escape_after_a_mapped_read_not_vouched_for():
+    # read 2 unmapped: written after read 8, which lies past it
+    assert offsets([(0, [0, 1]), (1, [4, 6]), (2, [7, 3]), (3, [8, 2]), (4, [5])]) == 0
+
+
+def test_long_read_escape_opening_the_last_block():
+    # not seen: the windows after its rank move by one read, half a span of
+    # two here; the last block leaves it out of its own median
+    assert offsets([(0, [0, 1]), (1, [4, 6]), (2, [7, 3, 8]), (3, [2, 5])], 4) == 0.5
+
+
+@pytest.mark.parametrize("contents,reading", [
+    ([1, 2, 3, 4, 0], 1.0),  # every block shifted by one
+    ([0, 2, 2, 3, 4], 1.0),  # one block answered with its neighbour's reads
+    ([0, 1, 1, 3, 4], 1.0),
+    ([1, 0, 2, 3, 4], 1.0),
+])
+def test_long_read_misplaced_block_fails(contents, reading):
+    checks = long_check([long_answer([0, 1, 2, 3, 4], contents)])
+    assert checks["block_offset_spans"] == reading and not oracle.passed(checks)

@@ -2,13 +2,16 @@
 tiny configuration, below the harness's look for a chip, and the check has
 to see each fault planted in the timed path.
 
-The tiny cells, their configuration, a traffic kind and a metric live under
+The tiny cells, their configurations, a traffic kind and a metric live under
 ``bench/tests/data``; the test lays them beside a copy of ``bench/`` and
-adds their manifest entries, editing no file of the benchmark.
+adds their manifest entries, editing no file of the benchmark. Each
+configuration's corpus is built once per module: a long read takes seconds
+of host encode.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import shutil
 import sys
@@ -27,10 +30,32 @@ import harness  # noqa: E402
 
 SEED = 5  # the traffic's seed; the tiny corpus (seed 1) holds a read with an N
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+CELLS = ["tiny.stream-kmer", "tiny.restream-2bit", "tiny-hifi.stream-kmer",
+         "tiny-ont.stream-kmer"]
 
 
 @pytest.fixture(scope="module")
-def tree(tmp_path_factory):
+def built_once(tmp_path_factory):
+    """``corpus.build`` with each configuration's container built once and
+    copied into every run's work directory."""
+    built = {}
+    build = harness.corpus_mod.build
+
+    def cached(config, workdir):
+        name = config["name"]
+        if name not in built:
+            built[name] = build(config, tmp_path_factory.mktemp(name))
+        path = Path(workdir) / built[name].path.name
+        shutil.copy(built[name].path, path)
+        return dataclasses.replace(built[name], path=path)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(harness.corpus_mod, "build", cached)
+        yield
+
+
+@pytest.fixture(scope="module")
+def tree(tmp_path_factory, built_once):
     """A copy of bench/ with the test data added as new files, and the
     manifest with the test cells' entries added."""
     root = tmp_path_factory.mktemp("bench")
@@ -58,7 +83,7 @@ def run(tree, cell, **kw):
     return res, lines
 
 
-@pytest.mark.parametrize("cell", ["tiny.stream-kmer", "tiny.restream-2bit"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_cell_runs_correct(tree, cell):
     res, lines = run(tree, cell)
     assert KEYS <= set(res) and list(res)[-1] == "checks"
@@ -143,7 +168,7 @@ def fault(monkeypatch):
     return plant
 
 
-@pytest.mark.parametrize("cell", ["tiny.stream-kmer", "tiny.restream-2bit"])
+@pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("kind", ["token", "half", "stale", "neighbour"])
 def test_fault_is_not_correct(tree, fault, cell, kind):
     fault(kind)

@@ -28,6 +28,7 @@ import dataclasses
 import time
 from typing import Optional, Union
 
+import jax
 import numpy as np
 
 from repro.core import tuning
@@ -259,7 +260,19 @@ class SageEncoder:
     round-trips every encoded block through the bucketed JAX decoder and
     demotes any mismatching read to the escape stream (the batch analogue
     of the reference's per-read ``_verify`` walk); False trusts the mapper
-    (benchmark-grade). The reference path always walks per read."""
+    (benchmark-grade). The reference path always walks per read.
+
+    ``stats`` after a batched encode: ``n_batch_mapped`` reads mapped in
+    batch and ``n_fallback`` sent to the sequential mapper (reads holding an
+    N, chimera-splitting attempts); the device DP's ``align_calls``,
+    ``align_lanes``, ``align_rows`` (read bases aligned) and
+    ``align_rows_padded`` (rows the DP ran, lane and length padding
+    included); ``n_escaped``, ``verify_rounds`` and the host seconds
+    ``t_map``, ``t_pack`` and ``t_verify``. Profiler spans ``sage.encode.map``
+    (whole batched map), ``.align`` (each DP call), ``.traceback``,
+    ``.fallback`` (each sequential ``map_read``), ``.pack`` and ``.verify``
+    mark the same stages on a trace; they cost nothing without a profiler
+    session."""
 
     def __init__(
         self,
@@ -270,8 +283,6 @@ class SageEncoder:
         max_classes: int = 4,
         batched: bool = True,
         verify: bool = True,
-        batch_min: int = 4,
-        batch_max_len: int = 4096,
     ) -> None:
         self.cons = np.asarray(consensus, dtype=np.uint8)
         self.token_target = token_target
@@ -280,8 +291,6 @@ class SageEncoder:
         self.max_classes = max_classes
         self.batched = batched
         self.verify = verify
-        self.batch_min = batch_min
-        self.batch_max_len = batch_max_len
         self.stats: dict[str, Union[int, float]] = {}
 
     # ------------------------------------------------------------------ map
@@ -469,10 +478,7 @@ class SageEncoder:
         from repro.genomics.batch_map import batch_map_reads
 
         map_stats: dict = {}
-        segs_list = batch_map_reads(
-            self.mapper, reads, min_batch=self.batch_min,
-            batch_max_len=self.batch_max_len, stats=map_stats,
-        )
+        segs_list = batch_map_reads(self.mapper, reads, stats=map_stats)
         self.stats.update(map_stats)
         out: list[Optional[list[SegRecord]]] = []
         for read, segs in zip(reads, segs_list):
@@ -781,19 +787,21 @@ class SageEncoder:
             if rounds > len(reads) + 2:
                 raise RuntimeError("encode verify loop failed to converge")
             tp = time.perf_counter()
-            perm, per_read = self._ordered_records(reads, recs_list, escaped)
-            tbl = SegTable.from_records(per_read)
-            blk_read = self._blockize_table(tbl)
-            sf = self._pack_table(tbl, blk_read, opt_level, rs)
+            with jax.profiler.TraceAnnotation("sage.encode.pack"):
+                perm, per_read = self._ordered_records(reads, recs_list, escaped)
+                tbl = SegTable.from_records(per_read)
+                blk_read = self._blockize_table(tbl)
+                sf = self._pack_table(tbl, blk_read, opt_level, rs)
             t_pack += time.perf_counter() - tp
             if not self.verify or sf.meta.n_blocks == 0:
                 break
             tv = time.perf_counter()
-            # opt levels < 3 pack mbb/idl in a layout the decoder does not
-            # read (the paper's ablation sizes only); verify the records
-            # through an opt-4 shadow container instead
-            sfv = sf if opt_level >= 3 else self._pack_table(tbl, blk_read, 4, rs)
-            fails = self._decode_verify_failures(sfv, [reads[p] for p in perm])
+            with jax.profiler.TraceAnnotation("sage.encode.verify"):
+                # opt levels < 3 pack mbb/idl in a layout the decoder does not
+                # read (the paper's ablation sizes only); verify the records
+                # through an opt-4 shadow container instead
+                sfv = sf if opt_level >= 3 else self._pack_table(tbl, blk_read, 4, rs)
+                fails = self._decode_verify_failures(sfv, [reads[p] for p in perm])
             t_verify += time.perf_counter() - tv
             if not fails:
                 break

@@ -325,8 +325,12 @@ class ReadMapper:
             return None
         return best
 
-    def _chimeric(self, read: np.ndarray, cands: list[tuple[int, int, int, int]]) -> Optional[list[Segment]]:
-        """Split the read into ≤top_n segments from distinct seed clusters."""
+    def _chimera_pieces(
+        self, size: int, cands: list[tuple[int, int, int, int]]
+    ) -> Optional[list[tuple[int, int, int]]]:
+        """Where a read of ``size`` bases splits: (read_lo, read_hi,
+        cand_pos) of each of ≤top_n pieces from distinct seed clusters, or
+        None when it cannot split."""
         # greedy: order clusters by read-interval start; keep non-overlapping
         picked: list[tuple[int, int, int]] = []  # (qlo, qhi, cand)
         for votes, cand, qlo, qhi in sorted(cands, key=lambda c: -c[0])[: self.top_n]:
@@ -341,13 +345,23 @@ class ReadMapper:
         bounds = [0]
         for a, b in zip(picked[:-1], picked[1:]):
             bounds.append((a[1] + b[0]) // 2)
-        bounds.append(read.size)
-        segs: list[Segment] = []
+        bounds.append(size)
+        pieces = []
         for (qlo, qhi, cand), lo, hi in zip(picked, bounds[:-1], bounds[1:]):
-            sub = read[lo:hi]
-            if sub.size < 20:
+            if hi - lo < 20:
                 return None
-            aln = banded_align(sub, self.cons, cand + (lo - qlo), self._band(sub.size))
+            pieces.append((lo, hi, cand + (lo - qlo)))
+        return pieces
+
+    def _chimeric(self, read: np.ndarray, cands: list[tuple[int, int, int, int]]) -> Optional[list[Segment]]:
+        """Split the read into ≤top_n segments from distinct seed clusters."""
+        pieces = self._chimera_pieces(read.size, cands)
+        if pieces is None:
+            return None
+        segs: list[Segment] = []
+        for lo, hi, cand in pieces:
+            sub = read[lo:hi]
+            aln = banded_align(sub, self.cons, cand, self._band(sub.size))
             if aln is None:
                 return None
             segs.append(Segment(lo, hi, aln))

@@ -63,7 +63,7 @@ def test_batch_map_matches_sequential_mapper():
     rs = sample_read_set(ref, "illumina", depth=2, seed=3)
     m = ReadMapper(ref)
     seq = [m.map_read(r) for r in rs.reads]
-    bat = batch_map_reads(m, rs.reads, min_batch=2)
+    bat = batch_map_reads(m, rs.reads)
     assert len(seq) == len(bat)
     for a, b in zip(seq, bat):
         assert (a is None) == (b is None)
@@ -122,13 +122,13 @@ def test_lookup_matches_bruteforce_expansion():
 def test_batched_encoder_bit_identical_all_opt_levels(opt_level):
     ref, rs = _mixed_read_set(seed=7)
     sf_ref = SageEncoder(ref, token_target=4096, batched=False).encode(rs, opt_level=opt_level)
-    sf_bat = SageEncoder(ref, token_target=4096, batch_min=2).encode(rs, opt_level=opt_level)
+    sf_bat = SageEncoder(ref, token_target=4096).encode(rs, opt_level=opt_level)
     assert sf_ref.diff(sf_bat) == []
 
 
 def test_batched_encoder_lossless_and_escape_stats():
     ref, rs = _mixed_read_set(seed=11, n=40)
-    enc_b = SageEncoder(ref, token_target=4096, batch_min=2)
+    enc_b = SageEncoder(ref, token_target=4096)
     enc_r = SageEncoder(ref, token_target=4096, batched=False)
     sf_b, sf_r = enc_b.encode(rs), enc_r.encode(rs)
     assert multiset(d.seq for d in refdec.decode_all(sf_b)) == multiset(rs.reads)
@@ -137,13 +137,20 @@ def test_batched_encoder_lossless_and_escape_stats():
 
 
 def test_batched_encoder_variable_length_fallback_parity():
-    """Length groups below min_batch fall back to the sequential mapper but
-    still pack through the columnar path — output must stay identical."""
+    """Long reads of every length map through the batched device DP (one
+    program per length bucket, each lane at its own length) and pack
+    through the columnar path — output must stay identical, and only reads
+    holding an N fall back to the sequential mapper."""
     ref = make_reference(40_000, seed=5)
     rs = sample_read_set(ref, "ont", depth=1, max_reads=8, seed=6)
     sf_ref = SageEncoder(ref, token_target=8192, batched=False).encode(rs)
-    sf_bat = SageEncoder(ref, token_target=8192).encode(rs)
+    enc = SageEncoder(ref, token_target=8192)
+    sf_bat = enc.encode(rs)
     assert sf_ref.diff(sf_bat) == []
+    assert len({r.size for r in rs.reads}) == len(rs.reads)  # every length its own
+    assert enc.stats["n_fallback"] == sum(bool((r == 4).any()) for r in rs.reads)
+    assert enc.stats["n_batch_mapped"] + enc.stats["n_fallback"] == len(rs.reads)
+    assert enc.stats["align_calls"] < len(rs.reads) <= enc.stats["align_lanes"]
 
 
 def test_batched_encoder_empty_read_set():
@@ -204,6 +211,6 @@ if HAVE_HYP:
         ref, rs = _mixed_read_set(seed=seed, n=14, ref_len=6000)
         for opt in (0, 4):
             sf_ref = SageEncoder(ref, token_target=2048, batched=False).encode(rs, opt_level=opt)
-            sf_bat = SageEncoder(ref, token_target=2048, batch_min=2).encode(rs, opt_level=opt)
+            sf_bat = SageEncoder(ref, token_target=2048).encode(rs, opt_level=opt)
             assert sf_ref.diff(sf_bat) == []
         assert multiset(d.seq for d in refdec.decode_all(sf_bat)) == multiset(rs.reads)

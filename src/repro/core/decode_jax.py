@@ -9,8 +9,14 @@ cumsum/gather/scatter passes over fixed-capacity arrays:
   var-width fields    -> prefix-sum widths + 64-bit-window gathers
   delta positions     -> segmented cumsum
   indel bookkeeping   -> explicit (mbb==3) detection + rank cumsums
-  read reconstruction -> scatter subs/ins/del onto the token axis + gathers
-                         from the 2-bit consensus window
+  segment and read    -> per-segment values scattered as differences at the
+  structure              segment starts + one prefix scan (``_spread``); no
+                         search loop, no per-token lookup into a
+                         per-segment table, on the mismatch or token axis
+  read reconstruction -> scatter subs/ins/del onto the token axis (insertion
+                         spans by running max) + gathers whose index is data:
+                         the 2-bit consensus window, the inserted and escaped
+                         bases, and the reverse-complement
 
 Blocks are decoded independently (vmap / Pallas grid) — the analogue of the
 paper's per-NAND-channel parallel units. All device math is int32/uint32 and
@@ -127,12 +133,31 @@ def decode_adaptive(
     return jnp.where(k < n, vals, 0)
 
 
-def _seg_cumsum(vals: jax.Array, first_idx: jax.Array) -> jax.Array:
-    """Inclusive cumsum of ``vals`` restarted at each segment; ``first_idx``
-    maps element -> index of its segment's first element."""
-    gc = jnp.cumsum(vals)
-    gc_excl = gc - vals
-    return gc - gc_excl[jnp.clip(first_idx, 0, vals.shape[0] - 1)]
+def _row_index(idx: jax.Array, k: int, n: int) -> jax.Array:
+    """Flat indices of slot ``idx`` (>= 0) in each row of a ``(k, n)`` array;
+    slots past ``n`` drop instead of spilling into the next row. Scattering
+    scalars into the flat array keeps a 1-D token array's TPU layout, where a
+    ``(k, n)`` scatter of k-wide windows would lay the k rows along the
+    128-wide lane axis."""
+    rows = n * jnp.arange(k, dtype=jnp.int32)[:, None]
+    return jnp.where(idx < n, idx + rows, k * n).ravel()
+
+
+def _spread(seg_vals: jax.Array, starts: jax.Array, n: int) -> jax.Array:
+    """Per-segment values spread over ``n`` slots: slot ``t`` takes each row
+    of ``seg_vals`` (shape ``(k, R)``) at the last segment whose start is at
+    or before ``t`` (``starts`` nondecreasing). That is the segment
+    ``searchsorted(starts + lens, t, side="right")`` (clipped to ``R - 1``)
+    names, zero-length segments and masked ones past the end included. Each
+    segment's difference from the one before is scattered at its start
+    (starts >= n drop) and one cumsum telescopes them, for all k rows at once:
+    no search loop and no slot-length lookup into a per-segment table."""
+    k = seg_vals.shape[0]
+    diffs = jnp.diff(seg_vals, axis=-1, prepend=0)
+    marks = jnp.zeros((k * n,), seg_vals.dtype).at[_row_index(starts, k, n)].add(
+        diffs.ravel(), mode="drop"
+    )
+    return jnp.cumsum(marks.reshape(k, n), axis=1)
 
 
 # --------------------------------------------------------------------------
@@ -195,19 +220,24 @@ def decode_block_arrays(
     unzig = (map_vals >> 1) ^ -(map_vals & 1)
     pos = jnp.where(cont == 1, acc + unzig, acc)  # corner pos unused
 
-    # ---- token layout ----------------------------------------------------
-    starts_i = jnp.cumsum(lens) - lens  # (R,) exclusive
-    seg_of_t = jnp.searchsorted(jnp.cumsum(lens), ar_t, side="right").astype(jnp.int32)
-    seg_of_t = jnp.clip(seg_of_t, 0, R - 1)
-    seg_start_t = starts_i[seg_of_t]
-    j = ar_t - seg_start_t  # read-coordinate within segment
+    # ---- segment and read structure (R axis) -----------------------------
+    starts_i = jnp.cumsum(lens) - lens  # (R,) first token of each segment
+    esc_lens = jnp.where(corner == 1, lens, 0)
+    esc_start_seg = jnp.cumsum(esc_lens) - esc_lens
+    read_first = seg_mask & (cont == 0)
+    read_id_seg = jnp.maximum(jnp.cumsum(read_first.astype(jnp.int32)) - 1, 0)  # owning read
+    rid_scatter = jnp.where(read_first, read_id_seg, R)
+    read_rev = jnp.zeros((R,), jnp.int32).at[rid_scatter].max(rev, mode="drop")
+    read_pos = jnp.full((R,), -1, jnp.int32).at[rid_scatter].max(
+        jnp.where(corner == 1, -1, pos), mode="drop"
+    )
+    read_start = jnp.zeros((R,), jnp.int32).at[rid_scatter].max(starts_i, mode="drop")
+    read_len = jnp.zeros((R,), jnp.int32).at[jnp.where(seg_mask, read_id_seg, R)].add(lens, mode="drop")
+    read_corner = jnp.zeros((R,), jnp.int32).at[rid_scatter].max(corner, mode="drop")
 
-    # ---- mismatch -> segment mapping ------------------------------------
-    cnt_ends = jnp.cumsum(cnts)
-    cnt_starts = cnt_ends - cnts
-    seg_of_m = jnp.clip(jnp.searchsorted(cnt_ends, ar_m, side="right").astype(jnp.int32), 0, R - 1)
+    # ---- mismatch records ------------------------------------------------
+    cnt_starts = jnp.cumsum(cnts) - cnts  # (R,) first mismatch of each segment
     mp_deltas = decode_adaptive(blk["mpg"], blk["mpa"], n_mism, classes["mp"], M)
-    p_m = _seg_cumsum(mp_deltas, cnt_starts[seg_of_m])  # read coords
     mbb = extract_fields(blk["mbb"], 2 * ar_m, jnp.full((M,), 2, jnp.int32))
     mbb = jnp.where(mism_mask, mbb, 0)
 
@@ -225,9 +255,22 @@ def decode_block_arrays(
     del_len_m = jnp.where((is_ind == 1) & (is_ins == 0), ilen_m, 0)
     ibs_off_m = jnp.cumsum(ins_len_m) - ins_len_m  # exclusive, in bases
 
-    # ---- consensus cursor per mismatch (for sub rank -> base) -----------
-    shift_m_excl = _seg_cumsum(del_len_m - ins_len_m, cnt_starts[seg_of_m]) - (del_len_m - ins_len_m)
-    cursor_m = pos[seg_of_m] + p_m + shift_m_excl
+    # ---- mismatch -> token and consensus cursor --------------------------
+    # Two prefix sums that restart at each segment's first mismatch: the read
+    # coordinate (deltas) and the consensus shift of the indels before it.
+    # Each restart is the block-wide sum at the segment's first mismatch (an
+    # R-length lookup), spread over the mismatches with the segment's token
+    # start and consensus position.
+    shift_m = del_len_m - ins_len_m
+    p_incl = jnp.cumsum(mp_deltas)
+    shift_excl = jnp.cumsum(shift_m) - shift_m
+    first_m = jnp.clip(cnt_starts, 0, M - 1)
+    p_first = (p_incl - mp_deltas)[first_m]
+    seg_tok_m, seg_cur_m = _spread(
+        jnp.stack([starts_i - p_first, pos - p_first - shift_excl[first_m]]), cnt_starts, M
+    )
+    t_m = p_incl + seg_tok_m  # token index of each mismatch
+    cursor_m = p_incl + shift_excl + seg_cur_m  # its consensus index
     cw = blk["cons"]
 
     def cons_at(idx: jax.Array) -> jax.Array:
@@ -238,60 +281,57 @@ def decode_block_arrays(
     sub_base = mbb + (mbb >= cons_b_m).astype(jnp.int32)  # rank -> base
 
     # ---- scatter mismatches onto the token axis -------------------------
-    t_m = starts_i[seg_of_m] + p_m
     t_m_safe = jnp.where(mism_mask, jnp.clip(t_m, 0, C - 1), C)  # C -> dropped
     is_sub = mism_mask & (mbb < 3)
     sub_t = jnp.full((C,), -1, jnp.int32).at[jnp.where(is_sub, t_m_safe, C)].set(sub_base, mode="drop")
     # deletions: shift consensus index for t >= t_m
     del_at = jnp.zeros((C,), jnp.int32).at[t_m_safe].add(del_len_m, mode="drop")
-    del_shift_t = _seg_cumsum(del_at, seg_start_t)
-    # insertions: mark coverage [t_m, t_m + L)
-    is_ins_m = mism_mask & (is_ins == 1)
-    ins_start_mark = jnp.full((C,), -1, jnp.int32).at[jnp.where(is_ins_m, t_m_safe, C)].max(t_m, mode="drop")
-    last_ins_start = jax.lax.cummax(ins_start_mark)
-    ins_len_t0 = jnp.zeros((C,), jnp.int32).at[jnp.where(is_ins_m, t_m_safe, C)].max(ins_len_m, mode="drop")
-    ins_off_t0 = jnp.zeros((C,), jnp.int32).at[jnp.where(is_ins_m, t_m_safe, C)].max(ibs_off_m, mode="drop")
-    lis = jnp.clip(last_ins_start, 0, C - 1)
-    inside_ins = (last_ins_start >= 0) & (ar_t - last_ins_start < ins_len_t0[lis]) & tok_mask
-    ibs_idx_t = ins_off_t0[lis] + (ar_t - last_ins_start)
+    # insertions cover [t_m, t_m + L). They never overlap and come in token
+    # order, so each one's end and its token-to-ibs offset t_m - ibs_off never
+    # decrease: a running max carries the last insertion's pair forward.
+    ins_slot = jnp.where(mism_mask & (is_ins == 1), t_m_safe, C)
+    ins_marks = jnp.zeros((2 * C,), jnp.int32).at[_row_index(ins_slot, 2, C)].max(
+        jnp.stack([t_m + ins_len_m, t_m - ibs_off_m]).ravel(), mode="drop"
+    )
+    ins_end_t, ins_base_t = jax.lax.cummax(ins_marks.reshape(2, C), axis=1)
+    inside_ins = (ar_t < ins_end_t) & tok_mask
+    ibs_idx_t = ar_t - ins_base_t
     ibs_val_t = extract_fields(blk["ibs"], 2 * jnp.clip(ibs_idx_t, 0, caps.insb), jnp.full((C,), 2, jnp.int32))
 
-    # ---- consensus-derived tokens ----------------------------------------
+    # ---- per-segment values on the token axis (one spread) --------------
+    # The consensus index restarts its consumed-token count and its deletion
+    # shift at each segment's first token: both restarts fold into the
+    # segment's consensus offset, next to the escape offset, the corner flag
+    # and the read's strand and mirror point (2 * start + len - 1).
     consumes = jnp.where(tok_mask & ~inside_ins, 1, 0)
-    cc_t = _seg_cumsum(consumes, seg_start_t) - consumes  # exclusive
-    cons_idx_t = pos[seg_of_t] + cc_t + del_shift_t
-    cons_tok = cons_at(cons_idx_t)
+    cc_excl = jnp.cumsum(consumes) - consumes
+    del_incl = jnp.cumsum(del_at)
+    first_t = jnp.clip(starts_i, 0, C - 1)
+    seg_cons = pos - cc_excl[first_t] - (del_incl - del_at)[first_t]
+    cons_t, esc_t, corner_t, rev_t, mirror_t = _spread(
+        jnp.stack([
+            seg_cons,
+            esc_start_seg - starts_i,
+            corner,
+            read_rev[read_id_seg],
+            2 * read_start[read_id_seg] + read_len[read_id_seg] - 1,
+        ]),
+        starts_i,
+        C,
+    )
 
-    # ---- escape (corner) segments ----------------------------------------
-    esc_lens = jnp.where(corner == 1, lens, 0)
-    esc_start_seg = jnp.cumsum(esc_lens) - esc_lens
-    esc_idx_t = esc_start_seg[seg_of_t] + j
-    esc_val_t = extract_fields(blk["esc"], 3 * jnp.clip(esc_idx_t, 0, caps.escb), jnp.full((C,), 3, jnp.int32))
-    is_corner_t = corner[seg_of_t] == 1
-
+    # ---- consensus-derived and escape (corner) tokens -------------------
+    cons_tok = cons_at(cc_excl + del_incl + cons_t)
+    esc_val_t = extract_fields(blk["esc"], 3 * jnp.clip(ar_t + esc_t, 0, caps.escb), jnp.full((C,), 3, jnp.int32))
     tokens = jnp.where(
-        is_corner_t,
+        corner_t == 1,
         esc_val_t,
         jnp.where(inside_ins, ibs_val_t, jnp.where(sub_t >= 0, sub_t, cons_tok)),
     )
 
-    # ---- per-read grouping + reverse-complement --------------------------
-    read_first = seg_mask & (cont == 0)
-    read_id_seg = jnp.cumsum(read_first.astype(jnp.int32)) - read_first.astype(jnp.int32)
-    rid_scatter = jnp.where(read_first, read_id_seg, R)
-    read_rev = jnp.zeros((R,), jnp.int32).at[rid_scatter].max(rev, mode="drop")
-    read_pos = jnp.full((R,), -1, jnp.int32).at[rid_scatter].max(
-        jnp.where(corner == 1, -1, pos), mode="drop"
-    )
-    read_start = jnp.zeros((R,), jnp.int32).at[rid_scatter].max(starts_i, mode="drop")
-    read_len = jnp.zeros((R,), jnp.int32).at[jnp.where(seg_mask, read_id_seg, R)].add(lens, mode="drop")
-    read_corner = jnp.zeros((R,), jnp.int32).at[rid_scatter].max(corner, mode="drop")
-
-    rid_t = read_id_seg[seg_of_t]
-    rev_t = read_rev[rid_t] == 1
-    rstart_t = read_start[rid_t]
-    rlen_t = read_len[rid_t]
-    src = jnp.where(rev_t, rstart_t + (rlen_t - 1 - (ar_t - rstart_t)), ar_t)
+    # ---- reverse-complement ----------------------------------------------
+    rev_t = rev_t == 1
+    src = jnp.where(rev_t, mirror_t - ar_t, ar_t)
     out = tokens[jnp.clip(src, 0, C - 1)]
     out = jnp.where(rev_t & (out < 4), 3 - out, out)
     out = jnp.where(tok_mask, out, PAD_BASE).astype(jnp.int8)
